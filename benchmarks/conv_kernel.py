@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core.rebranch import ReBranchSpec
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cnn
 
 
@@ -127,6 +128,7 @@ def sketch_flops_line(c_in: int = 1024, k: int = 3, d_ratio: int = 4) -> str:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", type=int, default=104,
                     help="input resolution (DarkNet-19 native: 416)")

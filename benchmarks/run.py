@@ -15,6 +15,8 @@ tables and the roofline report.
 import argparse
 import json
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _sections(fast: bool) -> list:
     from benchmarks import (table1_macro, fig12_area_map,
@@ -39,6 +41,7 @@ def parse_line(section: str, line: str) -> dict:
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="skip the training-based figures (10/11)")

@@ -40,6 +40,7 @@ from repro import plan as plan_lib
 from repro.core import rebranch
 from repro.core.rebranch import ReBranchSpec
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cnn
 
 MODEL_ID = "vgg8-swap-bench"
@@ -176,6 +177,7 @@ def run() -> list[str]:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke: 2 scenarios, short training")

@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _make_load(users: int, vocab: int, gen: int, seed: int = 0,
                prompt_min: int = 6, prompt_max: int = 24):
@@ -246,6 +248,7 @@ def run() -> list[str]:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="small load (CI smoke): 4 users, 12 tokens")

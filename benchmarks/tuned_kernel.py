@@ -30,6 +30,7 @@ import numpy as np
 
 from benchmarks.conv_kernel import _time, darknet_layer_shapes
 from repro.core.rebranch import ReBranchSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cnn
 from repro.tune import table as tune_table
 
@@ -95,6 +96,7 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for line in run():
         print(line, flush=True)

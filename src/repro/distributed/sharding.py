@@ -55,17 +55,12 @@ _state = threading.local()
 
 
 def current_mesh() -> Mesh | None:
-    m = getattr(_state, "mesh", None)
-    if m is not None:
-        return m
-    # fall back to the global mesh context (`with mesh:`)
-    try:
-        env = jax.interpreters.pxla.thread_resources.env
-        if env.physical_mesh and not env.physical_mesh.empty:
-            return env.physical_mesh
-    except Exception:
-        pass
-    return None
+    """The mesh bound by the innermost :func:`use_mesh`, or None.
+
+    Read at trace time (inside ``jit``), so it is this module's own
+    thread-local binding and not JAX's ambient mesh context, which
+    ``jax.sharding.get_mesh`` refuses to read under a trace."""
+    return getattr(_state, "mesh", None)
 
 
 def current_rules() -> dict[str, tuple[str, ...]]:

@@ -58,12 +58,6 @@ from repro.kernels.rebranch_conv import (
     rebranch_conv_pallas, trunk_conv_pallas,
 )
 
-try:                                     # jax >= 0.5
-    shard_map = jax.shard_map
-except AttributeError:                   # jax < 0.5: experimental home
-    from jax.experimental.shard_map import shard_map
-
-
 @dataclasses.dataclass(frozen=True)
 class HaloPlan:
     """Static geometry of one H-sharded conv (all fields trace-static).
@@ -187,8 +181,8 @@ def sharded_trunk_conv(cfg: cim_lib.CiMConfig, stride: int, padding: str,
                                  stride=stride, padding="VALID")
 
     spec = P(None, axis, None, None)
-    out = shard_map(body, mesh=mesh, in_specs=(spec, P(), P()),
-                    out_specs=spec, check_rep=False)(xp, w_q, w_scale)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(spec, P(), P()),
+                        out_specs=spec, check_vma=False)(xp, w_q, w_scale)
     return _finish(out, plan)
 
 
@@ -244,8 +238,8 @@ def sharded_rebranch_conv(x, w_q, w_scale, c, core, u,
                                     block_m=bm, block_n=bn, block_k=bk)
 
     spec = P(None, axis, None, None)
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(spec, P(), P(), P(), P(), P()),
-                    out_specs=spec, check_rep=False)(
-                        xp, w_q, w_scale, c, core, u)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(spec, P(), P(), P(), P(), P()),
+                        out_specs=spec, check_vma=False)(
+                            xp, w_q, w_scale, c, core, u)
     return _finish(out, plan)

@@ -11,6 +11,11 @@ Only *bit-identical* tilings are legal table entries: a tiling may change
 how fast a kernel runs, never what it returns.  The autotuner enforces
 that at generation time and the kernels re-check the k-partition
 defensively at lookup time (see ``repro.kernels.tiling``).
+
+A table records the backend it was tuned on (``meta.backend``).  Its
+timings say nothing about another backend, so :func:`lookup` ignores a
+table tuned elsewhere: a CPU-tuned ``impl="direct"`` entry must not turn
+the ``pallas_call`` kernels off on a TPU.
 """
 
 from __future__ import annotations
@@ -20,6 +25,8 @@ import dataclasses
 import json
 import os
 from typing import Iterator, Mapping
+
+import jax
 
 DIM_ORDERS = ("mnk", "kmn")
 IMPLS = ("grid", "direct")
@@ -78,22 +85,25 @@ def key(kernel: str, mode: str, dtype: str, m: int, k: int, n: int) -> str:
 
 _cache: dict | None = None
 _cache_path: str | None = None
+_cache_backend: str | None = None            # meta.backend of the cache
 _stack: list[dict[str, Tiling] | None] = []   # None == lookups disabled
 
 
 def load_table(path: str | None = None) -> dict[str, Tiling]:
     """Load (and cache) the tuning table.  Missing file -> empty table."""
-    global _cache, _cache_path
+    global _cache, _cache_path, _cache_backend
     p = path or _DEFAULT_PATH
     if _cache is not None and _cache_path == p:
         return _cache
     entries: dict[str, Tiling] = {}
+    backend = None
     if os.path.exists(p):
         with open(p) as f:
             raw = json.load(f)
         for k, v in raw.get("entries", {}).items():
             entries[k] = Tiling.from_json(v)
-    _cache, _cache_path = entries, p
+        backend = raw.get("meta", {}).get("backend")
+    _cache, _cache_path, _cache_backend = entries, p, backend
     return entries
 
 
@@ -108,19 +118,25 @@ def save_table(entries: Mapping[str, Tiling], path: str,
 
 
 def invalidate_cache() -> None:
-    global _cache, _cache_path
-    _cache, _cache_path = None, None
+    global _cache, _cache_path, _cache_backend
+    _cache, _cache_path, _cache_backend = None, None, None
 
 
 def lookup(kernel: str, mode: str, dtype: str,
            m: int, k: int, n: int) -> Tiling | None:
-    """Look up a tuned tiling; ``None`` means use the kernel default."""
+    """Look up a tuned tiling; ``None`` means use the kernel default.
+
+    Context overrides apply as given.  The checked-in table applies only
+    on the backend it was tuned on (its ``meta.backend``)."""
     if _stack:
         top = _stack[-1]
         if top is None:          # disabled() context
             return None
         return top.get(key(kernel, mode, dtype, m, k, n))
-    return load_table().get(key(kernel, mode, dtype, m, k, n))
+    entries = load_table()
+    if _cache_backend != jax.default_backend():
+        return None
+    return entries.get(key(kernel, mode, dtype, m, k, n))
 
 
 @contextlib.contextmanager

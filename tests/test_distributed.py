@@ -27,6 +27,7 @@ def test_sharded_train_step_matches_single_device():
     unsharded one — sharding is semantics-preserving."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import configs, optim
         from repro.core import rebranch
         from repro.data import synthetic
@@ -49,7 +50,7 @@ def test_sharded_train_step_matches_single_device():
         _, _, m1 = jax.jit(step)(t, f, opt, batch)
 
         # sharded 4x2 mesh
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         with shd.use_mesh(mesh), mesh:
             t_sh, f_sh, opt_sh, _ = steps_lib.model_state_shardings(cfg, mesh)
             in_sh = steps_lib.batch_shardings(
@@ -68,6 +69,7 @@ def test_serve_step_sharded_decode():
     """Sharded decode on a mesh produces the same next token."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import configs
         from repro.distributed import sharding as shd
         from repro.launch import steps as steps_lib
@@ -80,7 +82,7 @@ def test_serve_step_sharded_decode():
         step = steps_lib.make_serve_step(cfg)
         tok1, _ = jax.jit(step)(params, batch, cache)
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         with shd.use_mesh(mesh), mesh:
             t_sh, f_sh, _, _ = steps_lib.model_state_shardings(cfg, mesh)
             from repro.core import rebranch
@@ -101,19 +103,16 @@ def test_int8_compressed_allreduce_matches_plain():
     """shard_map int8 EF all-reduce ~= plain psum mean over the data axis."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from functools import partial
         from jax.sharding import PartitionSpec as P
         from repro.optim import compress
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:              # jax < 0.5: experimental home
-            from jax.experimental.shard_map import shard_map
 
-        mesh = jax.make_mesh((8,), ('data',))
+        mesh = make_mesh((8,), ('data',))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 1e-3
         err = jnp.zeros((8, 64))
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P('data'), P('data')),
                  out_specs=(P('data'), P('data')))
         def compressed(gs, es):
@@ -135,6 +134,7 @@ def test_elastic_restore_across_meshes(tmp_path):
     """Checkpoint on an 8-device mesh, restore on 4 devices (elastic)."""
     out = _run(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import configs, optim
         from repro.checkpoint import manager as ckpt
         from repro.core import rebranch
@@ -148,7 +148,7 @@ def test_elastic_restore_across_meshes(tmp_path):
         ckpt.save({str(tmp_path)!r}, 3, t, opt, params)
 
         # restore re-sharded onto a DIFFERENT (smaller) mesh
-        mesh = jax.make_mesh((2, 2), ('data', 'model'))
+        mesh = make_mesh((2, 2), ('data', 'model'))
         with shd.use_mesh(mesh), mesh:
             from repro.launch import steps as steps_lib
             t_sh, f_sh, opt_sh, _ = steps_lib.model_state_shardings(cfg, mesh)

@@ -70,9 +70,10 @@ class TestCollectives:
             "src")
         code = textwrap.dedent("""
             import jax, jax.numpy as jnp
+            from repro.launch.mesh import make_mesh
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.launch import hlo_cost
-            mesh = jax.make_mesh((8,), ('x',))
+            mesh = make_mesh((8,), ('x',))
             def f(a, b):
                 y = a @ b                     # contraction sharded -> psum
                 return y

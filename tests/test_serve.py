@@ -31,6 +31,8 @@ from repro.serve.scheduler import ContinuousBatcher
 
 MODEL_ID = "gemma-2b-smoke"
 MAX_LEN = 48
+# batched vs solo float32 logits: max |diff| over max |logit|, per row
+LOGIT_RTOL = 1e-5
 
 
 @pytest.fixture(scope="module")
@@ -647,7 +649,13 @@ class TestPerRowCacheRows:
         """Rows at different lengths in ONE cache must each write their
         own ring slot: before the fix, every row wrote row 0's slot,
         corrupting any batch whose lengths diverged (exactly the
-        continuous-batching state)."""
+        continuous-batching state).
+
+        Batched vs solo is a tolerance, not bitwise: XLA may reduce a
+        batch-3 dot in another order than a batch-1 one (measured on CPU:
+        up to 1.8e-7 of the row's largest float32 logit).  A row that
+        read another row's slot is off by the logits' own scale, far
+        above ``LOGIT_RTOL``; greedy tokens must agree exactly."""
         model, _, params = cell
         prompts = _prompts(3, model.cfg.vocab_size, seed=11)  # 6,7,8 long
         solo_caches = []
@@ -667,9 +675,14 @@ class TestPerRowCacheRows:
         for i in range(3):
             solo_logits, _ = jax.jit(model.decode_step)(
                 params, tok[i:i + 1], solo_caches[i])
+            got = np.asarray(batched_logits[i])
+            want = np.asarray(solo_logits[0])
+            err = np.max(np.abs(got - want)) / np.max(np.abs(want))
+            assert err <= LOGIT_RTOL, \
+                f"row {i} (len {prompts[i].size}) diverged: rel err {err}"
             np.testing.assert_array_equal(
-                np.asarray(batched_logits[i]), np.asarray(solo_logits[0]),
-                err_msg=f"row {i} (len {prompts[i].size}) diverged")
+                np.argmax(got, -1), np.argmax(want, -1),
+                err_msg=f"row {i} greedy token diverged")
 
 # ---------------------------------------------------------------------------
 # speculative decode (ISSUE 10 tentpole)

@@ -130,6 +130,7 @@ def test_halo_doesnt_fit_fallback_warns_once():
     out = _run(textwrap.dedent("""
         import warnings
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro import engine as engine_lib
         from repro.core import cim as cim_lib
         from repro.core import rebranch
@@ -142,7 +143,7 @@ def test_halo_doesnt_fit_fallback_warns_once():
         # H=8 over 8 shards -> 1 row/shard < the 5x5 kernel's 2-row halo:
         # infeasible, the engine must fall back unsharded (and say so)
         x = jax.random.normal(jax.random.PRNGKey(1), (1, 8, 8, 8))
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         eng = engine_lib.get("pallas_sharded")
         with shd.use_mesh(mesh), mesh:
             with warnings.catch_warnings(record=True) as w1:
@@ -173,6 +174,7 @@ def test_sharded_trunk_conv_bit_identical_sweep():
     no-halo fast path and the uneven-shard general path included)."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import engine as engine_lib
         from repro.core import cim as cim_lib
         from repro.distributed import sharding as shd
@@ -185,8 +187,8 @@ def test_sharded_trunk_conv_bit_identical_sweep():
         key = jax.random.PRNGKey(0)
         checked = 0
         for n_dev in (1, 2, 4):
-            mesh = jax.make_mesh((n_dev, 1), ('data', 'model'),
-                                 devices=jax.devices()[:n_dev])
+            mesh = make_mesh((n_dev, 1), ('data', 'model'),
+                             devices=jax.devices()[:n_dev])
             for k in (1, 3):
                 p = cnn.init_conv(jax.random.fold_in(key, k), k, 20, 12,
                                   rebranch.ReBranchSpec())
@@ -217,13 +219,14 @@ def test_sharded_conv_fidelity_modes():
     differently), so the parity contract is under a common pipeline."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import engine as engine_lib
         from repro.core import cim as cim_lib, rebranch
         from repro.distributed import sharding as shd
         from repro.models import cnn
 
-        mesh = jax.make_mesh((4, 1), ('data', 'model'),
-                             devices=jax.devices()[:4])
+        mesh = make_mesh((4, 1), ('data', 'model'),
+                         devices=jax.devices()[:4])
         p = cnn.init_conv(jax.random.PRNGKey(0), 3, 20, 12,
                           rebranch.ReBranchSpec())
         w_q, w_scale = p['rom']['w_q'], p['rom']['w_scale']
@@ -250,14 +253,15 @@ def test_sharded_rebranch_conv_and_ste_grad():
     XLA conv."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import cim as cim_lib, rebranch
         from repro.distributed import sharding as shd
         from repro.kernels import halo_conv
         from repro.kernels.rebranch_conv import rebranch_conv_pallas
         from repro.models import cnn
 
-        mesh = jax.make_mesh((4, 1), ('data', 'model'),
-                             devices=jax.devices()[:4])
+        mesh = make_mesh((4, 1), ('data', 'model'),
+                         devices=jax.devices()[:4])
         cfg = cim_lib.CiMConfig(mode='ideal')
         p = cnn.init_conv(jax.random.PRNGKey(0), 3, 20, 12,
                           rebranch.ReBranchSpec())
@@ -296,13 +300,14 @@ def test_darknet_and_resnet_trunk_convs_bit_identical():
     sharded and unsharded engines on a 4-device mesh."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import engine as engine_lib
         from repro.core import cim as cim_lib, rebranch
         from repro.distributed import sharding as shd
         from repro.models import cnn
 
-        mesh = jax.make_mesh((4, 1), ('data', 'model'),
-                             devices=jax.devices()[:4])
+        mesh = make_mesh((4, 1), ('data', 'model'),
+                         devices=jax.devices()[:4])
         cfg = cim_lib.CiMConfig(mode='ideal')
         eng_sh = engine_lib.get('pallas_sharded')
         eng_pl = engine_lib.get('pallas')
@@ -357,12 +362,13 @@ def test_compile_model_mesh_cnn_forward():
     out = _run("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import deploy
         from repro.core import cim as cim_lib, rebranch
         from repro.models import cnn
 
-        mesh = jax.make_mesh((4, 1), ('data', 'model'),
-                             devices=jax.devices()[:4])
+        mesh = make_mesh((4, 1), ('data', 'model'),
+                         devices=jax.devices()[:4])
         spec = dataclasses.replace(rebranch.ReBranchSpec(),
                                    cim=cim_lib.CiMConfig(mode='ideal'))
         for name in ('darknet19', 'resnet18'):

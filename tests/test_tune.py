@@ -84,6 +84,20 @@ class TestTable:
             assert table.lookup("trunk_conv", "ideal", "float32",
                                 8, 256, 8) == t
 
+    def test_table_tuned_on_another_backend_is_ignored(self, tmp_path,
+                                                       monkeypatch):
+        """A table's entries apply only on the backend named in its
+        meta: CPU-tuned 'direct' entries must not steer the TPU path."""
+        geo = ("trunk_conv", "ideal", "float32", 8, 256, 8)
+        t = table.Tiling(64, 64, 256, "mnk", "direct")
+        for backend, want in ((jax.default_backend(), t),
+                              ("another-backend", None)):
+            p = tmp_path / f"{backend}.json"
+            table.save_table({table.key(*geo): t}, str(p),
+                             meta={"backend": backend})
+            monkeypatch.setattr(table, "_DEFAULT_PATH", str(p))
+            assert table.lookup(*geo) == want, backend
+
     def test_tiling_validation(self):
         with pytest.raises(ValueError):
             table.Tiling(128, 128, 512, dim_order="nkm")
