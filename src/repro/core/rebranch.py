@@ -295,10 +295,11 @@ def apply_linear(params, x, spec: ReBranchSpec, t1_axes=None,
         # is pure XLA on the branch tensors.  Branchless ROM sites
         # contribute zero (their whole signal lives in the trunk).
         if spec.branch_enabled and "core" in sram:
-            c = rom["C"].astype(x.dtype)
-            u = rom["U"].astype(x.dtype)
-            core = sram["core"].astype(x.dtype)
-            y = (x @ c) @ (core @ u)
+            with jax.named_scope("branch"):
+                c = rom["C"].astype(x.dtype)
+                u = rom["U"].astype(x.dtype)
+                core = sram["core"].astype(x.dtype)
+                y = (x @ c) @ (core @ u)
         else:
             y = jnp.zeros((*x.shape[:-1], rom["w_q"].shape[-1]), x.dtype)
         b = sram.get("b")
@@ -314,23 +315,25 @@ def apply_linear(params, x, spec: ReBranchSpec, t1_axes=None,
                              rom["C"], sram["core"], rom["U"])
         b = sram.get("b")
         return y if b is None else y + b.astype(x.dtype)
-    y = eng.matmul(spec.cim, x, rom["w_q"], rom["w_scale"],
-                   out_axes=out_axes)
+    with jax.named_scope("trunk"):
+        y = eng.matmul(spec.cim, x, rom["w_q"], rom["w_scale"],
+                       out_axes=out_axes)
 
     if spec.branch_enabled and "core" in sram:
-        c = rom["C"].astype(x.dtype)
-        u = rom["U"].astype(x.dtype)
-        core = sram["core"].astype(x.dtype)
-        # Reassociated epilogue: (x@C) @ (core@U).  core@U is a tiny
-        # [d_in/D, d_out] precompute whose output sharding matches the
-        # trunk's, so the branch adds NO collectives and NO wide
-        # intermediate activation ((t1@core)@U would materialise a
-        # d_out/U-wide tensor and force an all-gather under TP).
-        t1 = x @ c
-        if t1_axes is not None:
-            from repro.distributed.sharding import shard
-            t1 = shard(t1, *t1_axes)
-        y = y + t1 @ (core @ u)
+        with jax.named_scope("branch"):
+            c = rom["C"].astype(x.dtype)
+            u = rom["U"].astype(x.dtype)
+            core = sram["core"].astype(x.dtype)
+            # Reassociated epilogue: (x@C) @ (core@U).  core@U is a tiny
+            # [d_in/D, d_out] precompute whose output sharding matches the
+            # trunk's, so the branch adds NO collectives and NO wide
+            # intermediate activation ((t1@core)@U would materialise a
+            # d_out/U-wide tensor and force an all-gather under TP).
+            t1 = x @ c
+            if t1_axes is not None:
+                from repro.distributed.sharding import shard
+                t1 = shard(t1, *t1_axes)
+            y = y + t1 @ (core @ u)
     b = sram.get("b")
     return y if b is None else y + b.astype(x.dtype)
 

@@ -54,10 +54,12 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _patch_matrix(x: jax.Array, kh: int, kw: int, stride: int, padding: str):
-    """im2col + flatten: NHWC -> (P [M, R], (n, oh, ow))."""
+    """im2col + flatten: NHWC -> (P [M, R], (n, oh, ow)), under the
+    named scope ``patches``."""
     n = x.shape[0]
-    patches, (oh, ow) = cim_lib.im2col(x, kh, kw, stride, padding)
-    return patches.reshape(n * oh * ow, patches.shape[-1]), (n, oh, ow)
+    with jax.named_scope("patches"):
+        patches, (oh, ow) = cim_lib.im2col(x, kh, kw, stride, padding)
+        return patches.reshape(n * oh * ow, patches.shape[-1]), (n, oh, ow)
 
 
 def _quant_rows(x: jax.Array):
@@ -178,17 +180,19 @@ def _stacked_patches(x, kh, kw, stride, padding):
 
     Produces exactly the same P [M, taps*C_in] as :func:`_patch_matrix`
     (tap-major layout), but through kh*kw strided views + one stack —
-    much cheaper for XLA:CPU than the gather-based im2col.
+    much cheaper for XLA:CPU than the gather-based im2col.  Under the
+    named scope ``patches``.
     """
     n, h, w, c_in = x.shape
     (ph0, ph1), oh = cim_lib.conv_pads(h, kh, stride, padding)
     (pw0, pw1), ow = cim_lib.conv_pads(w, kw, stride, padding)
-    xp = jnp.pad(x, ((0, 0), (ph0, ph1), (pw0, pw1), (0, 0)))
     hi = (oh - 1) * stride + 1
     wi = (ow - 1) * stride + 1
-    cols = [xp[:, i:i + hi:stride, j:j + wi:stride, :]
-            for i in range(kh) for j in range(kw)]
-    p = jnp.stack(cols, axis=3).reshape(n * oh * ow, kh * kw * c_in)
+    with jax.named_scope("patches"):
+        xp = jnp.pad(x, ((0, 0), (ph0, ph1), (pw0, pw1), (0, 0)))
+        cols = [xp[:, i:i + hi:stride, j:j + wi:stride, :]
+                for i in range(kh) for j in range(kw)]
+        p = jnp.stack(cols, axis=3).reshape(n * oh * ow, kh * kw * c_in)
     return p, (n, oh, ow), ((ph0, ph1), (pw0, pw1), hi, wi)
 
 

@@ -104,12 +104,14 @@ def apply_conv(params, x, spec: ReBranchSpec, stride: int = 1,
         return y if fuse else engine_base.finish(y, epilogue)
     trunk_ep = (epilogue.without_act() if has_branch else epilogue) \
         if fuse else None
-    y = eng.conv(spec.cim, x, rom["w_q"], rom["w_scale"],
-                 stride=stride, padding="SAME", epilogue=trunk_ep)
+    with jax.named_scope("trunk"):
+        y = eng.conv(spec.cim, x, rom["w_q"], rom["w_scale"],
+                     stride=stride, padding="SAME", epilogue=trunk_ep)
     if has_branch:
-        t = _conv(x, rom["C"].astype(x.dtype), 1)
-        t = _conv(t, params["sram"]["core"].astype(x.dtype), stride)
-        b = _conv(t, rom["U"].astype(x.dtype), 1)
+        with jax.named_scope("branch"):
+            t = _conv(x, rom["C"].astype(x.dtype), 1)
+            t = _conv(t, params["sram"]["core"].astype(x.dtype), stride)
+            b = _conv(t, rom["U"].astype(x.dtype), 1)
         if fuse:
             if epilogue.scale is not None:
                 b = b * epilogue.scale.astype(b.dtype)

@@ -47,10 +47,10 @@ def init_embedding(key, vocab: int, d: int, cfg: ArchConfig):
 
 
 def apply_embedding(params, ids, cfg: ArchConfig):
-    t_q = params["rom"]["table_q"]
-    t_s = params["rom"]["table_scale"]
-    emb = t_q[ids].astype(_dt(cfg)) * t_s[ids].astype(_dt(cfg))
-    return emb
+    with jax.named_scope("embed"):
+        t_q = params["rom"]["table_q"]
+        t_s = params["rom"]["table_scale"]
+        return t_q[ids].astype(_dt(cfg)) * t_s[ids].astype(_dt(cfg))
 
 
 def embedding_as_logits(params, x, cfg: ArchConfig):
@@ -237,7 +237,15 @@ def _decode_attention(q, k_cache, v_cache, valid_count):
 
 def apply_attention(params, x, cfg: ArchConfig, layer_idx: int,
                     positions=None, cache=None, decode: bool = False):
-    """Returns (out, new_cache_entry)."""
+    """Returns (out, new_cache_entry), under the named scope
+    ``attention`` (its KV-cache write under ``kv_write``)."""
+    with jax.named_scope("attention"):
+        return _attention(params, x, cfg, layer_idx, positions, cache,
+                          decode)
+
+
+def _attention(params, x, cfg: ArchConfig, layer_idx: int, positions,
+               cache, decode: bool):
     spec = cfg.rebranch
     b, s, d = x.shape
     h, kv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -291,14 +299,15 @@ def apply_attention(params, x, cfg: ArchConfig, layer_idx: int,
             table = cache["table"]                     # [B, NB]
             bs = cache["k"].shape[1]
             s_max = table.shape[1] * bs
-            for j in range(s):
-                slot = (length + j) % s_max
-                pb = table[rows, slot // bs]           # [B] physical block
-                off = slot % bs
-                k_cache = k_cache.at[pb, off].set(
-                    k[:, j].astype(k_cache.dtype))
-                v_cache = v_cache.at[pb, off].set(
-                    v[:, j].astype(v_cache.dtype))
+            with jax.named_scope("kv_write"):
+                for j in range(s):
+                    slot = (length + j) % s_max
+                    pb = table[rows, slot // bs]       # [B] physical block
+                    off = slot % bs
+                    k_cache = k_cache.at[pb, off].set(
+                        k[:, j].astype(k_cache.dtype))
+                    v_cache = v_cache.at[pb, off].set(
+                        v[:, j].astype(v_cache.dtype))
             k_view = _gather_paged(k_cache, table)
             v_view = _gather_paged(v_cache, table)
         else:
@@ -309,12 +318,13 @@ def apply_attention(params, x, cfg: ArchConfig, layer_idx: int,
             # slot corrupts every row whose length differs from row 0's
             # — the new KV lands inside an already-valid slot and the
             # true slot stays stale).
-            for j in range(s):
-                slot = (length + j) % s_max   # [B] ring for SWA layers
-                k_cache = k_cache.at[rows, slot].set(
-                    k[:, j].astype(k_cache.dtype))
-                v_cache = v_cache.at[rows, slot].set(
-                    v[:, j].astype(v_cache.dtype))
+            with jax.named_scope("kv_write"):
+                for j in range(s):
+                    slot = (length + j) % s_max   # [B] ring for SWA layers
+                    k_cache = k_cache.at[rows, slot].set(
+                        k[:, j].astype(k_cache.dtype))
+                    v_cache = v_cache.at[rows, slot].set(
+                        v[:, j].astype(v_cache.dtype))
             k_view, v_view = k_cache, v_cache
         if s == 1:
             valid = jnp.minimum(length + 1, s_max)
@@ -453,14 +463,16 @@ def init_mlp(key, cfg: ArchConfig, d_ff: int | None = None):
 
 def apply_mlp(params, x, cfg: ArchConfig):
     spec = cfg.rebranch
-    if cfg.mlp_type in ("swiglu", "geglu"):
-        g = rebranch.apply_linear(params["gate"], x, spec)
-        u = rebranch.apply_linear(params["up"], x, spec)
-        act = jax.nn.silu(g) if cfg.mlp_type == "swiglu" else jax.nn.gelu(g)
-        h = act * u
-    else:
-        h = jax.nn.gelu(rebranch.apply_linear(params["up"], x, spec))
-    h = shard(h, "batch", "seq", "mlp")
-    return rebranch.apply_linear(params["down"], h, spec,
-                                 t1_axes=("batch", "seq", "mlp"),
-                                 out_axes=("batch", "seq_sp", None))
+    with jax.named_scope("mlp"):
+        if cfg.mlp_type in ("swiglu", "geglu"):
+            g = rebranch.apply_linear(params["gate"], x, spec)
+            u = rebranch.apply_linear(params["up"], x, spec)
+            act = jax.nn.silu(g) if cfg.mlp_type == "swiglu" \
+                else jax.nn.gelu(g)
+            h = act * u
+        else:
+            h = jax.nn.gelu(rebranch.apply_linear(params["up"], x, spec))
+        h = shard(h, "batch", "seq", "mlp")
+        return rebranch.apply_linear(params["down"], h, spec,
+                                     t1_axes=("batch", "seq", "mlp"),
+                                     out_axes=("batch", "seq_sp", None))

@@ -123,19 +123,19 @@ def _token_embed(params, tokens, cfg: ArchConfig):
 
 
 def apply_head(params, x, cfg: ArchConfig):
-    """ln_f + readout projection on [..., d] -> [..., V] / [..., Q, V]."""
-    x = layers.apply_rmsnorm(params["ln_f"], x, cfg.norm_eps)
-    if cfg.num_codebooks:
-        logits = rebranch.apply_linear(params["codebook_head"], x,
-                                       spec_for(cfg, "codebook_head"))
-        logits = logits.reshape(*logits.shape[:-1], cfg.num_codebooks,
-                                cfg.vocab_size)
-    elif cfg.tie_embeddings:
-        logits = layers.embedding_as_logits(params["embed"], x, cfg)
-    else:
-        logits = rebranch.apply_linear(params["lm_head"], x,
-                                       spec_for(cfg, "lm_head"))
-    return logits
+    """ln_f + readout projection on [..., d] -> [..., V] / [..., Q, V],
+    under the named scope ``lm_head``."""
+    with jax.named_scope("lm_head"):
+        x = layers.apply_rmsnorm(params["ln_f"], x, cfg.norm_eps)
+        if cfg.num_codebooks:
+            logits = rebranch.apply_linear(params["codebook_head"], x,
+                                           spec_for(cfg, "codebook_head"))
+            return logits.reshape(*logits.shape[:-1], cfg.num_codebooks,
+                                  cfg.vocab_size)
+        if cfg.tie_embeddings:
+            return layers.embedding_as_logits(params["embed"], x, cfg)
+        return rebranch.apply_linear(params["lm_head"], x,
+                                     spec_for(cfg, "lm_head"))
 
 
 def _readout(params, x, cfg: ArchConfig):

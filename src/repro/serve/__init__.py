@@ -31,6 +31,9 @@ can feed it.  This package owns requests on top of
   * :mod:`repro.serve.server`    — the async front door shared by LM
     decode serving and ``cnn.CNNConfig`` forward-only serving:
     ``serve.load(model_id)`` returns a server with ``submit``.
+  * :mod:`repro.serve.trace`     — host spans of the serving path
+    (admission, prefill, decode dispatch, sampling, copies), kept in
+    memory while ``trace.enable()`` is on; off by default.
 
 Scenario multiplexing (``repro.scenario``): one resident cell serves N
 registered scenarios.  ``registry.scenario_store(model_id)`` holds the

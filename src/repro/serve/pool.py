@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import api
+from repro.serve import trace
 
 
 def _batch_axis(cfg) -> int:
@@ -122,6 +123,11 @@ class SlotPool:
         """Pre-verify hook for an ``n``-token speculative block: dense
         rows span the full horizon, nothing to grant (no-op; the paged
         pool grants the covering blocks here)."""
+
+    def counts(self) -> dict:
+        """Host-side KV counts for the serving spans: none, since dense
+        rows keep their lengths on the device only."""
+        return {}
 
     def rollback(self, new_lens: dict[int, int]) -> None:
         """Truncate rows to ``{slot: new_length}`` after a speculative
@@ -260,6 +266,14 @@ class PagedPool:
         fragmentation (every granted block position holds a live KV)."""
         used = self.blocks_in_use * self.block_size
         return self.live_tokens / used if used else 0.0
+
+    def counts(self) -> dict:
+        """Host-side KV counts (the serving spans' attributes): live
+        positions, blocks granted, blocks reserved, and the positions
+        the pool's usable blocks hold."""
+        return {"kv_live": self.live_tokens, "kv_blocks": self.blocks_in_use,
+                "kv_reserved": self.blocks_reserved,
+                "kv_positions": self.n_blocks * self.block_size}
 
     # -- admission -------------------------------------------------------
     def try_admit(self, total_len: int) -> int | None:
@@ -405,6 +419,7 @@ class PagedPool:
         first = api._first_layer(solo_cache)
         length = int(np.asarray(first["length"]).reshape(-1)[0])
         n_grant = -(-length // self.block_size)
+        trace.annotate(blocks=n_grant)
         while len(self._blocks[row]) < n_grant:
             self._grant(row)
         phys = jnp.asarray(self._blocks[row][:n_grant], jnp.int32)

@@ -69,6 +69,7 @@ import numpy as np
 
 from repro.models import api
 from repro.scenario import swap_params
+from repro.serve import trace
 from repro.serve.pool import SlotPool
 
 
@@ -94,6 +95,7 @@ class Request:
     admit_step: int = -1                  # tick the prefill ran
     finish_step: int = -1                 # tick the last token landed
     submit_s: float = 0.0                 # wall clock, for latency stats
+    admit_s: float = 0.0                  # wall clock at the first prefill
     finish_s: float = 0.0
     drafted: int = 0                      # draft tokens verified for this row
     matched: int = 0                      # of those, accepted (drafts only —
@@ -305,7 +307,8 @@ class ContinuousBatcher:
         """Adopt a finished solo prefill into the pool and put the
         request into the decode batch (its first token comes from the
         prefill logits, exactly like the standalone path)."""
-        self.pool.adopt(slot, solo)
+        with trace.span("pool.adopt", rid=req.rid):
+            self.pool.adopt(slot, solo)
         if self.spec_k and self.draft_source is not None:
             pass                          # injected drafter: no draft KV
         elif self.spec_k:
@@ -319,7 +322,10 @@ class ContinuousBatcher:
                 self.params, {"tokens": jnp.asarray(req.prompt[None])},
                 d_solo)
             self._draft_pool.adopt(slot, d_solo)
-        first = int(jnp.argmax(logits[0, -1]))
+        with trace.span("batcher.first_token", rid=req.rid):
+            first = int(jnp.argmax(logits[0, -1]))
+        trace.record("request.prefill", req.admit_s * 1e9,
+                     time.perf_counter_ns(), rid=req.rid)
         req.slot = slot
         req.admit_step = self.step_count
         req.tokens.append(first)
@@ -334,9 +340,7 @@ class ContinuousBatcher:
         chunk's logits yield the first token and the row activates."""
         req, slot, solo, pos = self._prefilling
         end = min(pos + self.prefill_chunk, req.prompt.size)
-        logits, solo = self._prefill(
-            self.params, {"tokens": jnp.asarray(req.prompt[None, pos:end])},
-            solo)
+        logits, solo = self._run_prefill(req, solo, pos, end)
         if end < req.prompt.size:
             self._prefilling = (req, slot, solo, end)
         else:
@@ -378,33 +382,52 @@ class ContinuousBatcher:
                 if self._prefilling is not None:
                     return
                 continue
-            logits, solo = self._prefill(
-                self.params, {"tokens": jnp.asarray(req.prompt[None])},
-                solo)
+            logits, solo = self._run_prefill(req, solo, 0, req.prompt.size)
             self._activate(req, slot, solo, logits)
+
+    def _run_prefill(self, req: Request, solo, pos: int, end: int):
+        """One prefill call over ``prompt[pos:end]`` into the solo
+        cache; the first call of a request stamps its admission."""
+        if pos == 0:
+            req.admit_s = time.perf_counter()
+            trace.record("request.queue", req.submit_s * 1e9,
+                         req.admit_s * 1e9, rid=req.rid)
+        with trace.span("batcher.prefill", rid=req.rid, start=pos, end=end):
+            return self._prefill(
+                self.params,
+                {"tokens": jnp.asarray(req.prompt[None, pos:end])}, solo)
 
     def step(self) -> bool:
         """One scheduler tick: retire / admit at the boundary (one
         prefill chunk at most), then one batched decode step — or, in
         speculative mode, one draft+verify round.  Returns False once
         idle."""
-        self._admit()
-        if not self._active:
+        with trace.span("batcher.step"):
+            with trace.span("batcher.admit"):
+                self._admit()
+            if not self._active:
+                return not self.idle
+            if self.spec_k:
+                return self._spec_step()
+            # paged pools grant each row's next block here; dense no-op
+            with trace.span("pool.prepare_step"):
+                self.pool.prepare_step()
+            with trace.span("batcher.decode", rows=len(self._active)):
+                if trace.enabled():
+                    trace.annotate(**self.pool.counts())
+                logits, cache = self._decode(
+                    self.params, jnp.asarray(self._tok), self.pool.cache)
+            self.pool.cache = cache
+            with trace.span("batcher.sample"):
+                nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1),
+                                 np.int32)
+            self.step_count += 1
+            with trace.span("batcher.retire"):
+                for slot, req in list(self._active.items()):
+                    req.tokens.append(int(nxt[slot]))
+                    self._tok[slot, 0] = nxt[slot]
+                    self._maybe_retire(req)
             return not self.idle
-        if self.spec_k:
-            return self._spec_step()
-        # paged pools grant each row's next block here; dense no-op
-        self.pool.prepare_step()
-        logits, cache = self._decode(
-            self.params, jnp.asarray(self._tok), self.pool.cache)
-        self.pool.cache = cache
-        nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
-        self.step_count += 1
-        for slot, req in list(self._active.items()):
-            req.tokens.append(int(nxt[slot]))
-            self._tok[slot, 0] = nxt[slot]
-            self._maybe_retire(req)
-        return not self.idle
 
     def _spec_step(self) -> bool:
         """One draft+verify round over the active batch.
@@ -424,31 +447,53 @@ class ContinuousBatcher:
         k = min(self.spec_k,
                 min(r.max_new_tokens - len(r.tokens)
                     for r in self._active.values()))
-        n = self.pool.n_slots
-        if self.draft_source is not None:
-            drafts = np.asarray(
-                self.draft_source(dict(self._active), self._tok.copy(), k),
-                np.int32).reshape(n, k)
-        else:
-            drafts = np.zeros((n, k), np.int32)
-            tok = self._tok
-            for j in range(k):
-                d_logits, d_cache = self._draft_decode(
-                    self.params, jnp.asarray(tok), self._draft_pool.cache)
-                self._draft_pool.cache = d_cache
-                nxt = np.asarray(jnp.argmax(d_logits[:, -1, :], axis=-1),
-                                 np.int32)
-                drafts[:, j] = nxt
-                tok = nxt[:, None]
+        with trace.span("batcher.draft", rows=len(self._active), k=k):
+            drafts = self._draft(k)
         # one batched verify over [last_token, d0..d_{k-2}]
         block = np.concatenate([self._tok, drafts[:, :k - 1]], axis=1)
-        self.pool.prepare_tokens(k)
-        logits, cache = self._verify(
-            self.params, jnp.asarray(block), self.pool.cache)
+        with trace.span("pool.prepare_step"):
+            self.pool.prepare_tokens(k)
+        with trace.span("batcher.verify", rows=len(self._active), k=k):
+            if trace.enabled():
+                trace.annotate(**self.pool.counts())
+            logits, cache = self._verify(
+                self.params, jnp.asarray(block), self.pool.cache)
         self.pool.cache = cache
-        truth = np.asarray(jnp.argmax(logits, axis=-1), np.int32)  # [N, k]
+        with trace.span("batcher.sample"):
+            truth = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         self.step_count += 1
         self.spec_rounds += 1
+        with trace.span("batcher.retire"):
+            roll = self._accept(drafts, truth, k)
+        with trace.span("pool.rollback", rows=len(roll)):
+            self.pool.rollback(roll)
+            if self.draft_source is None:
+                self._draft_pool.rollback(roll)
+        return not self.idle
+
+    def _draft(self, k: int) -> np.ndarray:
+        """[n_slots, k] drafted tokens: the injected ``draft_source``'s,
+        or k width-1 feeds of the branch-only draft model."""
+        n = self.pool.n_slots
+        if self.draft_source is not None:
+            return np.asarray(
+                self.draft_source(dict(self._active), self._tok.copy(), k),
+                np.int32).reshape(n, k)
+        drafts = np.zeros((n, k), np.int32)
+        tok = self._tok
+        for j in range(k):
+            d_logits, d_cache = self._draft_decode(
+                self.params, jnp.asarray(tok), self._draft_pool.cache)
+            self._draft_pool.cache = d_cache
+            nxt = np.asarray(jnp.argmax(d_logits[:, -1, :], axis=-1),
+                             np.int32)
+            drafts[:, j] = nxt
+            tok = nxt[:, None]
+        return drafts
+
+    def _accept(self, drafts, truth, k: int) -> dict[int, int]:
+        """Greedy accept-longest-prefix per row; retires finished rows
+        and returns ``{row: new_length}`` for survivors to truncate."""
         roll: dict[int, int] = {}
         for slot, req in list(self._active.items()):
             d, c = drafts[slot], truth[slot]
@@ -468,10 +513,7 @@ class ContinuousBatcher:
             self._maybe_retire(req)       # retirement releases the row:
             if slot in self._active and new_len != old_len + k:
                 roll[slot] = new_len      # survivors truncate the tail
-        self.pool.rollback(roll)
-        if self.draft_source is None:
-            self._draft_pool.rollback(roll)
-        return not self.idle
+        return roll
 
     def drain(self, max_steps: int | None = None) -> int:
         """Run until every submitted request finished; returns the

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import api, cnn
-from repro.serve import registry
+from repro.serve import registry, trace
 from repro.serve.pool import (PagedPool, SlotPool, suggest_paged,
                               suggest_slots)
 from repro.serve.scheduler import ContinuousBatcher
@@ -180,21 +180,27 @@ class CNNServer:
 
     def submit(self, images) -> np.ndarray:
         """images: [B, H, W, C] -> model outputs for all B rows."""
-        images = jnp.asarray(images)
-        if images.ndim == 3:
-            images = images[None]
-        outs = []
-        for lo in range(0, images.shape[0], self.n_slots):
-            chunk = images[lo:lo + self.n_slots]
-            pad = self.n_slots - chunk.shape[0]
-            if pad:
-                chunk = jnp.concatenate(
-                    [chunk, jnp.zeros((pad, *chunk.shape[1:]),
-                                      chunk.dtype)], 0)
-            out = self._forward(self.params, chunk)
-            outs.append(np.asarray(out[:self.n_slots - pad]
-                                   if pad else out))
-        return np.concatenate(outs, 0)
+        with trace.span("cnn.request"):
+            with trace.span("cnn.copy_in"):
+                images = jnp.asarray(images)
+                if images.ndim == 3:
+                    images = images[None]
+            trace.annotate(frames=images.shape[0])
+            outs = []
+            for lo in range(0, images.shape[0], self.n_slots):
+                with trace.span("cnn.copy_in"):
+                    chunk = images[lo:lo + self.n_slots]
+                    pad = self.n_slots - chunk.shape[0]
+                    if pad:
+                        chunk = jnp.concatenate(
+                            [chunk, jnp.zeros((pad, *chunk.shape[1:]),
+                                              chunk.dtype)], 0)
+                with trace.span("cnn.forward"):
+                    out = self._forward(self.params, chunk)
+                with trace.span("cnn.copy_out"):
+                    outs.append(np.asarray(out[:self.n_slots - pad]
+                                           if pad else out))
+            return np.concatenate(outs, 0)
 
     async def generate(self, image) -> np.ndarray:
         """Async single-image front door (symmetry with LMServer)."""
