@@ -11,8 +11,10 @@ Phases, all in this one process (it holds the chip; no child needs it):
        (``tpu_custom_call``).
   lm   qwen2-vl-2b (text path) at its published widths on a paged KV pool:
        4 seeded prompts of 32-128 tokens, 16 new tokens each, checked
-       against solo prefill+decode of the same prompts (greedy tokens
-       agree, or the two candidates are a near-tie within ``LM_TIE_RTOL``).
+       against solo prefill+decode of the same prompts on the params tree
+       as given, without the branch the server derives for serving
+       (greedy tokens agree, or the two candidates are a near-tie within
+       ``LM_TIE_RTOL``).
 
 Every phase serves seeded non-zero ReBranch cores (``with_branches``):
 the init's zero cores would make each branch add exactly 0, and a kernel
@@ -53,6 +55,7 @@ import numpy as np  # noqa: E402
 from repro import configs, deploy, serve  # noqa: E402
 from repro import plan as plan_lib  # noqa: E402
 from repro.configs import paper_models  # noqa: E402
+from repro.core import rebranch  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_cnn_serve_mesh  # noqa: E402
 from repro.models import cnn  # noqa: E402
@@ -143,12 +146,15 @@ def with_branches(params, seed: int, fan_in_axes):
 
 def _param_gb(params) -> dict:
     """GB of params: all, the int8 trunk (``w_q``), the float ROM
-    projections (``C``/``U``)."""
-    gb = {"all": 0.0, "w_q": 0.0, "C/U": 0.0}
+    projections (``C``/``U``), the branch derived for serving."""
+    gb = {"all": 0.0, "w_q": 0.0, "C/U": 0.0, "served": 0.0}
     for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
         name = getattr(path[-1], "key", None)
         gb["all"] += leaf.nbytes / 1e9
-        if name == "w_q":
+        if any(getattr(k, "key", None) == rebranch.SERVED_KEY
+               for k in path):
+            gb["served"] += leaf.nbytes / 1e9
+        elif name == "w_q":
             gb["w_q"] += leaf.nbytes / 1e9
         elif name in ("C", "U"):
             gb["C/U"] += leaf.nbytes / 1e9
@@ -255,7 +261,8 @@ def run_lm(cfg, *, seed: int, model_id: str = LM_ID, rows: int = LM_ROWS,
     again, t_warm = serve_all()
     _check(again == served, "lm: a second pass served other tokens")
 
-    params = server.params
+    # the reference runs on the tree as given, not the served one
+    # (``server.params``, which adds each site's derived branch)
     prefill, decode = jax.jit(model.prefill), jax.jit(model.decode_step)
     agree = total = 0
     worst, first_div = 0.0, None
@@ -280,7 +287,8 @@ def run_lm(cfg, *, seed: int, model_id: str = LM_ID, rows: int = LM_ROWS,
     return {"setup_s": t_setup, "cold_wall_s": t_cold, "warm_wall_s": t_warm,
             "prompt_lens": [int(p.size) for p in prompts],
             "agree": agree, "total": total, "worst_gap": worst,
-            "first_divergence": first_div, "param_gb": _param_gb(params)}
+            "first_divergence": first_div,
+            "param_gb": _param_gb(server.params)}
 
 
 def run_sharded(cfg, *, seed: int, n_chips: int) -> dict:

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -30,6 +31,9 @@ from repro.core import cim as cim_lib
 from repro.core import quant
 
 ROM_KEY = "rom"
+# A site's branch prepared for serving (``serving_branch``): derived, never
+# trainable, checkpointed or swapped.
+SERVED_KEY = "served"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -296,10 +300,7 @@ def apply_linear(params, x, spec: ReBranchSpec, t1_axes=None,
         # contribute zero (their whole signal lives in the trunk).
         if spec.branch_enabled and "core" in sram:
             with jax.named_scope("branch"):
-                c = rom["C"].astype(x.dtype)
-                u = rom["U"].astype(x.dtype)
-                core = sram["core"].astype(x.dtype)
-                y = (x @ c) @ (core @ u)
+                y = _branch(params, x)
         else:
             y = jnp.zeros((*x.shape[:-1], rom["w_q"].shape[-1]), x.dtype)
         b = sram.get("b")
@@ -321,21 +322,126 @@ def apply_linear(params, x, spec: ReBranchSpec, t1_axes=None,
 
     if spec.branch_enabled and "core" in sram:
         with jax.named_scope("branch"):
-            c = rom["C"].astype(x.dtype)
-            u = rom["U"].astype(x.dtype)
-            core = sram["core"].astype(x.dtype)
-            # Reassociated epilogue: (x@C) @ (core@U).  core@U is a tiny
-            # [d_in/D, d_out] precompute whose output sharding matches the
-            # trunk's, so the branch adds NO collectives and NO wide
-            # intermediate activation ((t1@core)@U would materialise a
-            # d_out/U-wide tensor and force an all-gather under TP).
-            t1 = x @ c
-            if t1_axes is not None:
-                from repro.distributed.sharding import shard
-                t1 = shard(t1, *t1_axes)
-            y = y + t1 @ (core @ u)
+            y = y + _branch(params, x, t1_axes)
     b = sram.get("b")
     return y if b is None else y + b.astype(x.dtype)
+
+
+def _branch(params, x, t1_axes=None):
+    """The branch term ``(x @ C) @ (core @ U)`` in ``x``'s dtype.
+
+    Reassociated epilogue: core@U is a tiny [d_in/D, d_out] precompute
+    whose output sharding matches the trunk's, so the branch adds NO
+    collectives and NO wide intermediate activation ((t1@core)@U would
+    materialise a d_out/U-wide tensor and force an all-gather under TP).
+    A site that :func:`serving_branch` prepared in ``x``'s dtype reads
+    its stored ``C`` and ``core @ U``; any other site casts and
+    multiplies its weights here, on every call.
+    """
+    served = params.get(SERVED_KEY)
+    if served is not None and served["W"].dtype != x.dtype:
+        served = None
+    if served is None:
+        c = params["rom"]["C"].astype(x.dtype)
+        u = params["rom"]["U"].astype(x.dtype)
+        core = params["sram"]["core"].astype(x.dtype)
+    else:
+        c = served["C"]
+    t1 = x @ c
+    if t1_axes is not None:
+        from repro.distributed.sharding import shard
+        t1 = shard(t1, *t1_axes)
+    return t1 @ (core @ u if served is None else served["W"])
+
+
+def _is_site(node) -> bool:
+    """A ReBranch linear site :func:`apply_linear` reads a branch from:
+    ``C``, ``core`` and ``U`` stacked alike (MoE expert stacks, whose
+    ``core`` carries an extra expert axis, run their own path)."""
+    rom, sram = node.get(ROM_KEY), node.get("sram")
+    if not (isinstance(rom, dict) and isinstance(sram, dict)
+            and "C" in rom and "U" in rom and "core" in sram):
+        return False
+    return rom["C"].ndim == rom["U"].ndim == sram["core"].ndim
+
+
+def _sites(node, out: list) -> None:
+    """Every site under ``node``, in a fixed walk order."""
+    if isinstance(node, dict):
+        if _is_site(node):
+            out.append(node)
+            return
+        for v in node.values():
+            _sites(v, out)
+    elif type(node) in (list, tuple):
+        for v in node:
+            _sites(v, out)
+
+
+def _core_at_u(core, u, dtype):
+    """``core.astype(dtype) @ u.astype(dtype)``, one layer at a time for
+    stacked sites, as each layer's call computed it."""
+    lead = core.shape[:-2]
+    if not lead:
+        return core.astype(dtype) @ u.astype(dtype)
+    w = jax.lax.map(lambda cu: cu[0].astype(dtype) @ cu[1].astype(dtype),
+                    (core.reshape(-1, *core.shape[-2:]),
+                     u.reshape(-1, *u.shape[-2:])))
+    return w.reshape(*lead, *w.shape[-2:])
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _derive(branches, dtype):
+    return [(None if c.dtype == dtype else c.astype(dtype),
+             _core_at_u(core, u, dtype)) for c, core, u in branches]
+
+
+def serving_branch(params, dtype):
+    """The params tree as serving reads it: each ReBranch site gains
+    ``params[SERVED_KEY] = {"C": C.astype(dtype), "W": core@U}``, the
+    branch in the activation ``dtype``, so a device call that applies it
+    (:func:`_branch`) converts and multiplies no weight.  One jitted
+    call for the whole tree; the expressions are those ``apply_linear``
+    evaluates per call.  The results are bit-identical on the CPU only:
+    on a TPU the compiler may round the derived ``core @ U`` otherwise
+    than inside each call, and fuse the served branch product into the
+    trunk's epilogue, so served and raw logits differ by bfloat16
+    rounding.  Every other leaf is the source's own array (no copy),
+    and a ``C`` already in ``dtype`` is stored as itself.  A derived
+    leaf goes stale when ``core`` changes: derive again from the new
+    tree.  Trees that train, checkpoint or swap are the source ones:
+    derived leaves are never trainable state.
+
+    Every site is derived, whatever its engine: the tree does not say
+    which spec governs a site.  A site whose engine fuses the branch
+    into its trunk kernel never reads its derived leaves, which then
+    cost their bytes (a bfloat16 ``C`` and ``W``) and nothing else.
+
+    Returns ``(served_tree, sites, nbytes)``: the sites derived (each
+    layer of a stacked site counts) and the bytes newly allocated.
+    """
+    dtype = jnp.dtype(dtype)
+    sites: list = []
+    _sites(params, sites)
+    derived = _derive([(s[ROM_KEY]["C"], s["sram"]["core"], s[ROM_KEY]["U"])
+                       for s in sites], dtype)
+    served = {id(s): {"C": s[ROM_KEY]["C"] if c is None else c, "W": w}
+              for s, (c, w) in zip(sites, derived)}
+
+    def build(node):
+        if isinstance(node, dict):
+            if id(node) in served:
+                return {**{k: v for k, v in node.items() if k != SERVED_KEY},
+                        SERVED_KEY: served[id(node)]}
+            return {k: build(v) for k, v in node.items()}
+        if type(node) in (list, tuple):
+            return type(node)(build(v) for v in node)
+        return node
+
+    n_sites = sum(math.prod(s[ROM_KEY]["C"].shape[:-2]) for s in sites)
+    nbytes = sum(w.nbytes + (0 if c is None else c.nbytes)
+                 for c, w in derived)
+    return build(params), n_sites, nbytes
 
 
 def freeze_to_rom(params_dense, key, spec: ReBranchSpec):

@@ -40,8 +40,9 @@ registered scenarios.  ``registry.scenario_store(model_id)`` holds the
 named branches (LRU device cache over host/checkpoint sources) and
 ``serve.load(model_id, scenario=...)`` / ``LMServer.swap_scenario``
 hot-swap the SRAM branch over the fixed ROM trunk at decode-step
-boundaries — zero trunk recompile, zero ROM traffic, in-flight
-requests finish on the scenario they were admitted under.
+boundaries — zero trunk recompile, no trunk traffic (an LM swap reads
+each site's ROM ``C`` and ``U`` once to derive its served branch),
+in-flight requests finish on the scenario they were admitted under.
 """
 
 from repro.serve.pool import (PagedPool, SlotPool,        # noqa: F401

@@ -51,10 +51,21 @@ BARRIER in the same FIFO queue requests ride: it applies at a
 decode-step boundary once every in-flight request has retired, so a
 request admitted under scenario A decodes entirely under A — bit-
 identical to a freshly compiled single-scenario cell — while requests
-tagged for B wait behind the barrier.  The swap itself is one donated
-combine (``scenario.swap_params``): trunk buffers alias through
-untouched, zero ROM traffic, no recompile (the params tree structure is
-unchanged, so the resident jit executables are reused as-is).
+tagged for B wait behind the barrier.  The swap is one donated combine
+(``scenario.swap_params``: trunk buffers alias through untouched, no
+recompile, since the params tree structure is unchanged and the
+resident jit executables are reused as-is), then one derivation of the
+served branch from the new cores (below), which reads every site's ROM
+``C`` and ``U`` once.
+
+Served branch: the batcher keeps the params tree it was given (the
+source, which swaps replace) and hands every device call a served tree
+built from it once (``rebranch.serving_branch``): each ReBranch site
+also holds ``C`` and ``core @ U`` in the activation dtype, so no prefill
+chunk or decode step converts or multiplies weights.  The served tree
+is built at construction and again after each swap, and references the
+source's buffers.  Every site is derived, those whose engine fuses the
+branch into its trunk kernel too, which never read the derived leaves.
 """
 
 from __future__ import annotations
@@ -67,6 +78,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import rebranch
 from repro.models import api
 from repro.scenario import swap_params
 from repro.serve import trace
@@ -143,10 +155,15 @@ class ContinuousBatcher:
                  prefill_chunk: int | None = None, spec_k: int = 0,
                  draft_source=None):
         self.model = model
-        self.params = params
         self.pool = pool
         self.scenario = scenario            # live branch label
         self.swap_count = 0                 # swaps applied so far
+        # ``_source`` is the params tree as given (what swaps replace);
+        # ``params`` the served tree every device call reads, rebuilt
+        # from it by _prepare_branch.
+        self._source = params
+        self.branch_prepares = 0            # served trees built so far
+        self._prepare_branch()
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         if spec_k and not api.supports_speculation(model.cfg):
@@ -294,12 +311,26 @@ class ContinuousBatcher:
         if len(req.tokens) >= req.max_new_tokens or hit_eos:
             self._finish(req)
 
+    def _prepare_branch(self) -> None:
+        """Build the served tree from the source: each ReBranch site's
+        branch in the activation dtype (``rebranch.serving_branch``), so
+        no prefill, decode, verify or draft call converts or multiplies
+        weights.  Runs at construction and after every swap."""
+        self.params = None                # free the stale derived leaves
+        with trace.span("batcher.prepare_branch"):
+            self.params, sites, nbytes = rebranch.serving_branch(
+                self._source, jnp.dtype(self.model.cfg.dtype))
+            trace.annotate(sites=sites, bytes=nbytes)
+        self.branch_prepares += 1
+
     def _apply_swap(self, sw: _Swap) -> None:
         """One donated combine: branch leaves replaced, trunk buffers
-        alias through in place (zero ROM traffic, no recompile — the
-        tree structure is unchanged so the jitted prefill/decode
-        executables are reused as-is)."""
-        self.params = swap_params(self.params, sw.branch)
+        alias through in place (no recompile — the tree structure is
+        unchanged so the jitted prefill/decode executables are reused
+        as-is), then the served tree is derived again from the new
+        branch, which reads each site's ROM ``C`` and ``U``."""
+        self._source = swap_params(self._source, sw.branch)
+        self._prepare_branch()
         self.scenario = sw.scenario
         self.swap_count += 1
 

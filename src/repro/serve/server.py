@@ -48,7 +48,8 @@ class LMServer:
     With a :class:`~repro.scenario.ScenarioStore` attached, one cell
     serves N scenarios: ``swap_scenario`` (or ``submit(...,
     scenario=...)``) queues a branch hot-swap behind the in-flight
-    requests — zero trunk recompile, zero ROM traffic, and every
+    requests — zero trunk recompile, no trunk traffic (one read of the
+    ROM ``C`` and ``U`` derives the served branch again), and every
     request decodes entirely under the scenario it was admitted with.
 
     ``spec_k > 0`` turns on speculative decode (the YOLoC-native
@@ -96,8 +97,10 @@ class LMServer:
 
     @property
     def params(self):
-        """The live params tree (the batcher owns it: scenario swaps
-        donate the old tree, so this is the ONE valid reference)."""
+        """The served params tree every device call reads: the source
+        tree plus each site's branch in the activation dtype
+        (``rebranch.serving_branch``).  The batcher owns it: scenario
+        swaps donate the old tree, so this is the ONE valid reference."""
         return self.batcher.params
 
     @property

@@ -6,6 +6,8 @@ scopes of the model's layers.
     ``rows`` sum to the tokens decoded, one ``request.queue`` and one
     ``request.prefill`` per request, spans nested as the batcher calls
     them, and KV attributes equal to the pool's own counts;
+  * one ``batcher.prepare_branch`` per served params tree built (at
+    construction, after each swap), and none with the recorder off;
   * the scopes change op metadata only: the compiled decode step and CNN
     forward are the same programs without them.
 """
@@ -19,9 +21,10 @@ import numpy as np
 import pytest
 
 from repro import serve
+from repro.core import rebranch
 from repro.models import cnn
 from repro.serve import trace
-from repro.serve.pool import PagedPool
+from repro.serve.pool import PagedPool, SlotPool
 from repro.serve.scheduler import ContinuousBatcher
 
 LM_ID = "qwen2-vl-2b-smoke"
@@ -158,6 +161,38 @@ def test_speculative_round_spans(cell):
     assert {"batcher.draft", "batcher.verify", "pool.rollback"} <= names
     assert "batcher.decode" not in names
     assert len(_named(spans, "batcher.verify")) == b.spec_rounds
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_prepare_branch_span_per_served_tree(cell, on):
+    """One ``batcher.prepare_branch`` at construction and one after each
+    swap, naming the sites derived and the bytes they hold; with the
+    recorder off the batcher prepares alike and nothing is recorded."""
+    model, params = cell
+    _, want_sites, want_bytes = rebranch.serving_branch(
+        params, jnp.dtype(model.cfg.dtype))
+    branch = jax.tree.map(lambda a: a + 0.01,
+                          rebranch.partition(params)[0])
+    trace.enable()                        # clears what was recorded
+    if not on:
+        trace.disable()
+    b = ContinuousBatcher(model, jax.tree.map(jnp.array, params),
+                          SlotPool(model, 2, MAX_LEN), scenario="a")
+    b.swap("b", branch)
+    b.submit(np.arange(5), 2, scenario="b")
+    b.drain(max_steps=20)
+    trace.disable()
+    spans = trace.snapshot()["spans"]
+    assert b.branch_prepares == 2 and b.swap_count == 1
+    if not on:
+        assert spans == []
+        return
+    prep = _named(spans, "batcher.prepare_branch")
+    assert [s["attrs"] for s in prep] == \
+        [{"sites": want_sites, "bytes": want_bytes}] * 2
+    assert want_sites == 7 * model.cfg.num_layers and want_bytes > 0
+    assert prep[0]["parent"] == -1
+    assert spans[prep[1]["parent"]]["name"] == "batcher.admit"
 
 
 def test_cap_counts_dropped_spans(monkeypatch):
