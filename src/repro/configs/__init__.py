@@ -1,9 +1,10 @@
-"""Architecture registry: the 10 assigned archs + the paper's own models.
+"""Architecture registry: the assigned archs + the paper's own models.
 
 Each module defines FULL (the published config, exercised only via the
 dry-run) and SMOKE (a reduced same-family config that runs a real
 forward/train step on CPU).  ``get(name)`` / ``get_smoke(name)`` look
-them up; ``ALL_ARCHS`` lists the assigned ten.
+them up; ``ALL_ARCHS`` lists the assigned ten and DeepSeek-V2-Lite (latent
+attention over a dense layer and MoE layers).
 """
 
 from __future__ import annotations
@@ -13,7 +14,7 @@ import importlib
 ALL_ARCHS = [
     "musicgen_large", "qwen2_vl_2b", "yi_34b", "qwen15_32b", "gemma_2b",
     "deepseek_67b", "granite_moe_3b", "qwen2_moe_a2_7b", "hymba_1_5b",
-    "falcon_mamba_7b",
+    "falcon_mamba_7b", "deepseek_v2_lite",
 ]
 
 # shape cells (assigned): name -> (seq_len, global_batch, kind)

@@ -343,26 +343,35 @@ def _branch(params, x, t1_axes=None):
         served = None
     if served is None:
         c = params["rom"]["C"].astype(x.dtype)
-        u = params["rom"]["U"].astype(x.dtype)
-        core = params["sram"]["core"].astype(x.dtype)
+        w = branch_weight(params["sram"]["core"], params["rom"]["U"],
+                          x.dtype)
     else:
-        c = served["C"]
+        c, w = served["C"], served["W"]
     t1 = x @ c
     if t1_axes is not None:
         from repro.distributed.sharding import shard
         t1 = shard(t1, *t1_axes)
-    return t1 @ (core @ u if served is None else served["W"])
+    return t1 @ w
+
+
+def branch_weight(core, u, dtype):
+    """``core @ U`` in ``dtype``: [d_in/D, d_out], or [E, d_in/D, d_out]
+    for an expert stack, whose per-expert cores share one ``U``.  The
+    one expression every call and :func:`serving_branch` evaluate."""
+    return jnp.matmul(core.astype(dtype), u.astype(dtype))
 
 
 def _is_site(node) -> bool:
-    """A ReBranch linear site :func:`apply_linear` reads a branch from:
-    ``C``, ``core`` and ``U`` stacked alike (MoE expert stacks, whose
-    ``core`` carries an extra expert axis, run their own path)."""
+    """A ReBranch site whose branch a call reads: a linear (``C``,
+    ``core`` and ``U`` stacked alike, read by :func:`apply_linear`) or
+    an expert stack (``core`` with one more, expert, axis; read by
+    ``models.moe.apply_expert_linear``)."""
     rom, sram = node.get(ROM_KEY), node.get("sram")
     if not (isinstance(rom, dict) and isinstance(sram, dict)
             and "C" in rom and "U" in rom and "core" in sram):
         return False
-    return rom["C"].ndim == rom["U"].ndim == sram["core"].ndim
+    return (rom["C"].ndim == rom["U"].ndim
+            and sram["core"].ndim in (rom["C"].ndim, rom["C"].ndim + 1))
 
 
 def _sites(node, out: list) -> None:
@@ -379,15 +388,15 @@ def _sites(node, out: list) -> None:
 
 
 def _core_at_u(core, u, dtype):
-    """``core.astype(dtype) @ u.astype(dtype)``, one layer at a time for
-    stacked sites, as each layer's call computed it."""
-    lead = core.shape[:-2]
+    """:func:`branch_weight`, one layer at a time for stacked sites, as
+    each layer's call computed it."""
+    lead = u.shape[:-2]                   # layer axes (U has no expert axis)
     if not lead:
-        return core.astype(dtype) @ u.astype(dtype)
-    w = jax.lax.map(lambda cu: cu[0].astype(dtype) @ cu[1].astype(dtype),
-                    (core.reshape(-1, *core.shape[-2:]),
+        return branch_weight(core, u, dtype)
+    w = jax.lax.map(lambda cu: branch_weight(cu[0], cu[1], dtype),
+                    (core.reshape(-1, *core.shape[len(lead):]),
                      u.reshape(-1, *u.shape[-2:])))
-    return w.reshape(*lead, *w.shape[-2:])
+    return w.reshape(*lead, *w.shape[1:])
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -400,7 +409,10 @@ def serving_branch(params, dtype):
     """The params tree as serving reads it: each ReBranch site gains
     ``params[SERVED_KEY] = {"C": C.astype(dtype), "W": core@U}``, the
     branch in the activation ``dtype``, so a device call that applies it
-    (:func:`_branch`) converts and multiplies no weight.  One jitted
+    (:func:`_branch`, ``models.moe.apply_expert_linear``) converts and
+    multiplies no weight.  An MoE expert stack is a site too: its
+    ``C`` and ``U`` are shared by the experts and its ``W`` holds one
+    ``core @ U`` per expert ([..., E, d_in/D, d_out]).  One jitted
     call for the whole tree; the expressions are those ``apply_linear``
     evaluates per call.  The results are bit-identical on the CPU only:
     on a TPU the compiler may round the derived ``core @ U`` otherwise

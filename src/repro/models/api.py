@@ -80,10 +80,12 @@ def supports_speculation(cfg: ArchConfig) -> bool:
     sequence-mixing layer to keep a full-horizon attention cache.  SWA
     rings can wrap within a k-block (the overwritten entry is
     unrecoverable) and ssm/hybrid recurrent state cannot rewind at all.
-    The predicate is currently the same as :func:`supports_paging`, for
-    the same structural reason (uniform full-attention horizon).
+    The predicate is :func:`supports_paging` (for the same structural
+    reason: a uniform full-attention horizon) less latent attention,
+    whose decode takes one token per row (no k-token verify).
     """
-    return (_mod(cfg) is transformer and cfg.sliding_window == 0)
+    return (_mod(cfg) is transformer and cfg.sliding_window == 0
+            and not cfg.mla)
 
 
 def verify_step(params, tokens, cfg: ArchConfig, cache):
@@ -93,11 +95,12 @@ def verify_step(params, tokens, cfg: ArchConfig, cache):
     if not supports_speculation(cfg):
         raise ValueError(
             f"{cfg.name!r} (family {cfg.family!r}, sliding_window="
-            f"{cfg.sliding_window}) cannot run speculative verify: "
-            f"rolling back rejected drafts needs a full-horizon "
-            f"attention cache (ssm/hybrid recurrent state cannot "
-            f"rewind; SWA rings overwrite entries a rollback would "
-            f"need)")
+            f"{cfg.sliding_window}, kv_lora_rank={cfg.kv_lora_rank}) "
+            f"cannot run speculative verify: rolling back rejected "
+            f"drafts needs a full-horizon attention cache (ssm/hybrid "
+            f"recurrent state cannot rewind; SWA rings overwrite "
+            f"entries a rollback would need), and latent attention "
+            f"has no k-token verify")
     return _mod(cfg).verify_step(params, tokens, cfg, cache)
 
 
@@ -179,8 +182,11 @@ def cache_geometry(cfg: ArchConfig, cache) -> tuple[int, int | None]:
     axis = 1 if cfg.scan_layers else 0
     first = _first_layer(cache)
     if isinstance(first, dict) and "table" in first:
-        table, k = first["table"], first["k"]          # [(L,) B, NB]
-        return table.shape[axis], table.shape[-1] * k.shape[axis + 1]
+        table = first["table"]                          # [(L,) B, NB]
+        blocks = first["latent" if "latent" in first else "k"]
+        return table.shape[axis], table.shape[-1] * blocks.shape[axis + 1]
+    if isinstance(first, dict) and "latent" in first:   # [L, B, S, W]
+        return first["latent"].shape[axis], first["latent"].shape[axis + 1]
     leaves = jax.tree.leaves(cache)
     if not leaves:
         raise ValueError("empty cache tree")

@@ -9,6 +9,18 @@ from repro.core.rebranch import ReBranchSpec
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2's
+    ``rope_scaling`` gives it."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str                    # dense | moe | ssm | hybrid | vlm | audio
@@ -32,6 +44,18 @@ class ArchConfig:
     moe_d_ff: int = 0
     moe_group_size: int = 1024     # dispatch group (memory/locality knob)
     moe_capacity_factor: float = 1.25
+    moe_norm_topk: bool = True     # renormalise the top-k gates to sum 1
+    shared_expert_gate: bool = True   # sigmoid gate on the shared experts
+    # (lo, hi): the contiguous range of routed experts this chip holds
+    # (expert parallelism); () holds all of them
+    experts_held: tuple = ()
+    first_k_dense: int = 0         # leading layers with a dense MLP (d_ff)
+    # --- multi-head latent attention (DeepSeek-V2); 0 -> off ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: YaRN | None = None
     # --- SSM (mamba-1) ---
     ssm_state: int = 0
     d_conv: int = 4
@@ -68,6 +92,25 @@ class ArchConfig:
                                self.d_model // self.num_heads)
         if self.dt_rank == 0 and self.ssm_state:
             object.__setattr__(self, "dt_rank", -(-self.d_model // 16))
+        if self.first_k_dense and not self.kv_lora_rank:
+            raise ValueError(
+                f"{self.name!r}: leading dense layers (first_k_dense) "
+                f"run only with the latent-attention cache")
+        if self.experts_held:
+            lo, hi = self.experts_held
+            if not 0 <= lo < hi <= self.num_experts:
+                raise ValueError(
+                    f"{self.name!r}: experts_held {self.experts_held} is "
+                    f"not a range inside [0, {self.num_experts})")
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        """(lo, hi) of the routed experts this chip holds."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def d_inner(self) -> int:

@@ -18,7 +18,9 @@ addresses too):
 
   transformer (dense/vlm/audio) : blocks.attn, blocks.mlp, lm_head |
                                   codebook_head (untied readouts only)
-  moe                           : blocks.attn, blocks.moe, lm_head
+  moe                           : blocks.attn, blocks.moe, lm_head;
+                                  blocks.mlp too with leading dense
+                                  layers (first_k_dense)
   ssm (mamba)                   : blocks.{in,x,dt,out}_proj, lm_head
   hybrid (hymba)                : blocks.attn, blocks.ssm.{...}_proj,
                                   blocks.mlp, lm_head
@@ -120,6 +122,14 @@ def _matmul_site(name: str, members, count: int = 1) -> Site:
 
 def _attn_members(cfg):
     d, h, kv, dh = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    if cfg.mla:                  # latent attention (models.mla)
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        r = cfg.kv_lora_rank
+        return [("q_proj", (d, h * (dn + dr))),
+                ("kv_a_proj_with_mqa", (d, r + dr)),
+                ("kv_b_proj", (r, h * (dn + dv))),
+                ("o_proj", (h * dv, d))]
     return [("q", (d, h * dh)), ("k", (d, kv * dh)),
             ("v", (d, kv * dh)), ("o", (h * dh, d))]
 
@@ -142,13 +152,16 @@ def _head_sites(cfg):
 
 
 def _moe_site(cfg) -> Site:
-    """Stacked ReBranch experts: weights cover all E experts; MACs per
-    token only the top-k active ones (plus the always-on shared expert).
+    """Stacked ReBranch experts: weights cover the E experts held here
+    (``cfg.experts_held``; all by default); MACs per token only the
+    top-k active ones, at most E (plus the always-on shared expert).
     The experts share ONE C/U sketch pair per stack with a per-expert
     core (models.moe.init_expert_linear), recorded in branch_members as
-    (d_in, d_out, rep=E, active=k)."""
-    d, ff, e = cfg.d_model, cfg.moe_d_ff or cfg.d_ff, cfg.num_experts
-    k = cfg.num_experts_per_tok
+    (d_in, d_out, rep=E, active=k).  Leading dense layers are not MoE
+    layers: the site counts the others."""
+    lo, hi = cfg.held_range
+    d, ff, e = cfg.d_model, cfg.moe_d_ff or cfg.d_ff, hi - lo
+    k = min(cfg.num_experts_per_tok, e)
     members = [("gate", (d, ff)), ("up", (d, ff)), ("down", (ff, d))]
     w_expert = sum(a * b for _, (a, b) in members)
     weights, macs = e * w_expert, k * w_expert
@@ -163,7 +176,7 @@ def _moe_site(cfg) -> Site:
         all_members += [(f"shared.{lbl}", shape) for lbl, shape in shared]
         branch += [(a, b, 1, 1) for _, (a, b) in shared]
     return Site(name="blocks.moe", kind="matmul", weights=weights,
-                macs=macs, count=cfg.num_layers,
+                macs=macs, count=cfg.num_layers - cfg.first_k_dense,
                 members=tuple((lbl, tuple(s)) for lbl, s in all_members),
                 branch_members=tuple(branch))
 
@@ -190,9 +203,12 @@ def _arch_sites(cfg) -> list:
                 _matmul_site("blocks.mlp", _mlp_members(cfg),
                              count=cfg.num_layers)] + _head_sites(cfg)
     if fam == "moe":
+        dense = ([_matmul_site("blocks.mlp", _mlp_members(cfg),
+                               count=cfg.first_k_dense)]
+                 if cfg.first_k_dense else [])
         return [_matmul_site("blocks.attn", _attn_members(cfg),
-                             count=cfg.num_layers),
-                _moe_site(cfg)] + _head_sites(cfg)
+                             count=cfg.num_layers)] + dense \
+            + [_moe_site(cfg)] + _head_sites(cfg)
     # ssm/hybrid init always build a real lm_head ReBranch group (their
     # families ignore tie_embeddings/num_codebooks), so the site is
     # unconditional — _head_sites applies transformer-family rules only

@@ -34,6 +34,8 @@ activation budget (:func:`suggest_slots` / :func:`suggest_paged`).
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -48,6 +50,23 @@ def _batch_axis(cfg) -> int:
     return 1 if getattr(cfg, "scan_layers", False) else 0
 
 
+@partial(jax.jit, static_argnames=("scan",), donate_argnums=(0,))
+def _scatter_blocks(pool_leaf, solo_leaf, phys, scan: bool):
+    """Write the first ``len(phys)`` blocks of a dense batch=1 leaf into
+    the physical blocks ``phys`` of a paged pool leaf.  The pool leaf is
+    donated, so adoption updates it in place: an undonated scatter would
+    hold a second copy of the whole pool leaf while it runs."""
+    n = phys.shape[0]
+    if scan:         # [L,P,bs,...] <- [L,1,max_len,...]
+        bs = pool_leaf.shape[2]
+        blocks = solo_leaf[:, 0, :n * bs].reshape(
+            solo_leaf.shape[0], n, bs, *solo_leaf.shape[3:])
+        return pool_leaf.at[:, phys].set(blocks.astype(pool_leaf.dtype))
+    bs = pool_leaf.shape[1]   # [P,bs,...] <- [1,max_len,...]
+    blocks = solo_leaf[0, :n * bs].reshape(n, bs, *solo_leaf.shape[2:])
+    return pool_leaf.at[phys].set(blocks.astype(pool_leaf.dtype))
+
+
 def _set_lengths(cache, new_lens: dict[int, int], scan: bool):
     """Scatter per-row ``length`` values into every layer of a serve
     cache (shared by both pools' speculative ``rollback``)."""
@@ -57,9 +76,10 @@ def _set_lengths(cache, new_lens: dict[int, int], scan: bool):
     if scan:       # stacked leaves: [L, B] lengths, broadcast over L
         layers = {**layers, "length": layers["length"].at[:, rows]
                   .set(vals[None])}
-        return {"layers": layers}
-    return {"layers": [{**ld, "length": ld["length"].at[rows].set(vals)}
-                       for ld in layers]}
+    else:
+        layers = [{**ld, "length": ld["length"].at[rows].set(vals)}
+                  for ld in layers]
+    return {**cache, "layers": layers}
 
 
 class SlotPool:
@@ -142,7 +162,8 @@ class SlotPool:
 
     # -- cache row transfer ---------------------------------------------
     def adopt(self, slot: int, solo_cache) -> None:
-        """Copy a batch=1 cache into ``slot``'s row, leaf by leaf."""
+        """Copy a batch=1 cache into ``slot``'s row, leaf by leaf (the
+        layers' leaves; the pool's own counters stay)."""
         axis = self._axis
 
         def put(pool_leaf, solo_leaf):
@@ -150,7 +171,8 @@ class SlotPool:
             return jax.lax.dynamic_update_index_in_dim(
                 pool_leaf, row.astype(pool_leaf.dtype), slot, axis)
 
-        self.cache = jax.tree.map(put, self.cache, solo_cache)
+        self.cache = {**self.cache, "layers": jax.tree.map(
+            put, self.cache["layers"], solo_cache["layers"])}
 
     def solo_cache(self):
         """A fresh batch=1 cache with this pool's geometry (for the
@@ -411,8 +433,10 @@ class PagedPool:
     def adopt(self, row: int, solo_cache) -> None:
         """Grant the blocks covering the solo-prefilled prompt and
         scatter its dense KV row into them, bitwise (one scatter per
-        leaf).  The row's device length is set from the solo cache, so
-        decode continues exactly where the solo path stood."""
+        position-major leaf of a layer: ``k`` and ``v``, or a latent
+        cache's ``latent``).  The row's device length is set from the
+        solo cache, so decode continues exactly where the solo path
+        stood; the pool's own counters stay."""
         if row not in self._blocks:
             raise ValueError(
                 f"row {row} was not admitted (call try_admit first)")
@@ -423,21 +447,12 @@ class PagedPool:
         while len(self._blocks[row]) < n_grant:
             self._grant(row)
         phys = jnp.asarray(self._blocks[row][:n_grant], jnp.int32)
-        span = n_grant * self.block_size
-        bs = self.block_size
 
         def put(pool_layer, solo_layer):
             out = dict(pool_layer)
-            for key in ("k", "v"):
-                pl, sl = pool_layer[key], solo_layer[key]
-                if self._scan:   # [L,P,bs,KV,Dh] <- [L,1,max_len,KV,Dh]
-                    blocks = sl[:, 0, :span].reshape(
-                        sl.shape[0], n_grant, bs, *sl.shape[3:])
-                    out[key] = pl.at[:, phys].set(blocks.astype(pl.dtype))
-                else:            # [P,bs,KV,Dh] <- [1,max_len,KV,Dh]
-                    blocks = sl[0, :span].reshape(n_grant, bs,
-                                                  *sl.shape[2:])
-                    out[key] = pl.at[phys].set(blocks.astype(pl.dtype))
+            for key in pool_layer.keys() - {"length", "table"}:
+                out[key] = _scatter_blocks(pool_layer[key], solo_layer[key],
+                                           phys, self._scan)
             if self._scan:
                 out["length"] = pool_layer["length"].at[:, row].set(length)
             else:
@@ -446,11 +461,11 @@ class PagedPool:
 
         layers = self.cache["layers"]
         if self._scan:
-            self.cache = {"layers": put(layers, solo_cache["layers"])}
+            layers = put(layers, solo_cache["layers"])
         else:
-            self.cache = {"layers": [
-                put(pl, sl) for pl, sl in zip(layers,
-                                              solo_cache["layers"])]}
+            layers = [put(pl, sl)
+                      for pl, sl in zip(layers, solo_cache["layers"])]
+        self.cache = {**self.cache, "layers": layers}
         self._len[row] = length      # joins prepare_step's advance loop
         self.sync()
 

@@ -170,8 +170,10 @@ class ContinuousBatcher:
             raise ValueError(
                 f"spec_k={spec_k} but {model.cfg.name!r} (family "
                 f"{model.cfg.family!r}, sliding_window="
-                f"{model.cfg.sliding_window}) cannot speculate: "
-                f"rollback needs a full-horizon attention cache "
+                f"{model.cfg.sliding_window}, kv_lora_rank="
+                f"{model.cfg.kv_lora_rank}) cannot speculate: rollback "
+                f"needs a full-horizon attention cache, and latent "
+                f"attention has no k-token verify "
                 f"(api.supports_speculation); pass spec_k=0")
         self.spec_k = int(spec_k)
         self.draft_source = draft_source
@@ -214,6 +216,7 @@ class ContinuousBatcher:
         self._tok = np.zeros((pool.n_slots, 1), np.int32)
         self._next_rid = 0
         self.step_count = 0
+        self._held_seen = None    # the pool's held-expert count, traced
 
     # -- front door ------------------------------------------------------
     def pending_scenario(self) -> str | None:
@@ -443,15 +446,20 @@ class ContinuousBatcher:
             # paged pools grant each row's next block here; dense no-op
             with trace.span("pool.prepare_step"):
                 self.pool.prepare_step()
-            with trace.span("batcher.decode", rows=len(self._active)):
+            with trace.span("batcher.decode",
+                            rows=len(self._active)) as decode_span:
                 if trace.enabled():
                     trace.annotate(**self.pool.counts())
                 logits, cache = self._decode(
                     self.params, jnp.asarray(self._tok), self.pool.cache)
             self.pool.cache = cache
             with trace.span("batcher.sample"):
-                nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1),
-                                 np.int32)
+                nxt = jnp.argmax(logits[:, -1, :], axis=-1)
+                if trace.enabled() and "counters" in cache:
+                    nxt = self._annotate_held(decode_span, nxt, cache)
+                else:
+                    self._held_seen = None
+                nxt = np.asarray(nxt, np.int32)
             self.step_count += 1
             with trace.span("batcher.retire"):
                 for slot, req in list(self._active.items()):
@@ -459,6 +467,19 @@ class ContinuousBatcher:
                     self._tok[slot, 0] = nxt[slot]
                     self._maybe_retire(req)
             return not self.idle
+
+    def _annotate_held(self, span, nxt, cache):
+        """While the recorder is on: the decode span gains
+        ``held_tokens``, the token assignments its step's held experts
+        computed, read with the sampled tokens in one transfer (the
+        sample waits for the step anyway)."""
+        held = cache["counters"]["held_tokens"]
+        nxt, total = jax.device_get((nxt, jnp.sum(held)))
+        total = int(total)
+        if self._held_seen is not None:
+            trace.annotate_span(span, held_tokens=total - self._held_seen)
+        self._held_seen = total
+        return nxt
 
     def _spec_step(self) -> bool:
         """One draft+verify round over the active batch.

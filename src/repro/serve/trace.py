@@ -119,6 +119,13 @@ def annotate(**attrs) -> None:
             _spans[stack[-1]][5].update(attrs)
 
 
+def annotate_span(span, **attrs) -> None:
+    """Add host-side integers to ``span`` (what :func:`span` returned),
+    open or closed."""
+    if _on and isinstance(span, _Span):
+        span.rec[5].update(attrs)
+
+
 def enabled() -> bool:
     return _on
 

@@ -42,6 +42,13 @@ FAMILY_CFGS = {
     "moe": _lm_cfg(name="m_plan", family="moe", num_experts=4,
                    num_experts_per_tok=2, moe_d_ff=32,
                    num_shared_experts=1),
+    # latent attention, a leading dense layer, 3 of 6 experts held
+    "mla_moe": _lm_cfg(name="d_plan", family="moe", num_layers=3,
+                       num_experts=6, num_experts_per_tok=2, moe_d_ff=32,
+                       num_shared_experts=2, shared_expert_gate=False,
+                       experts_held=(2, 5), first_k_dense=1,
+                       kv_lora_rank=16, qk_nope_head_dim=8,
+                       qk_rope_head_dim=4, v_head_dim=8),
     "ssm": _lm_cfg(name="s_plan", family="ssm", num_heads=0,
                    num_kv_heads=0, d_ff=0, ssm_state=4),
     "hybrid": _lm_cfg(name="h_plan", family="hybrid", ssm_state=4,

@@ -461,6 +461,24 @@ class TestPagedPool:
         np.testing.assert_array_equal(np.asarray(view[:length]),
                                       np.asarray(solo_row[:length]))
 
+    def test_adopt_updates_the_pool_in_place(self, cell):
+        """Adoption writes into the pool's own buffers: holding a second
+        copy of every pool leaf while the scatter runs would double the
+        pool's share of the device's memory."""
+        model, _, params = cell
+        pool = self._pool(model)
+        prompt = _prompts(1, model.cfg.vocab_size)[0]
+        solo = pool.solo_cache()
+        _, solo = jax.jit(model.prefill)(
+            params, {"tokens": jnp.asarray(prompt[None])}, solo)
+        row = pool.try_admit(prompt.size + 4)
+        before = api._first_layer(pool.cache)["k"]
+        ptr = before.unsafe_buffer_pointer()
+        pool.adopt(row, solo)
+        after = api._first_layer(pool.cache)["k"]
+        assert before.is_deleted()
+        assert after.unsafe_buffer_pointer() == ptr
+
     def test_suggest_paged_matches_dense_budget(self, cell):
         model, plan, _ = cell
         rows, blocks, bs = suggest_paged(model, plan, MAX_LEN,
